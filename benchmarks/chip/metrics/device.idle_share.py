@@ -1,0 +1,8 @@
+"""Device: the share of the profiled window in which no operation ran on
+the device, in %, from the trace."""
+
+
+def read(rec):
+    if rec.trace is None or rec.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - rec.trace.busy_s() / rec.trace.window_s)
