@@ -85,8 +85,6 @@ def headline(iters: int = 16) -> dict:
     events = tracer.chrome_events()
     reconciled, err = _spans_reconciled(tracer, traced)
     snap = tracer.metrics.snapshot()
-    gap_points = sum(v["n"] for k, v in snap.items()
-                     if k.startswith("modelled_vs_measured."))
     return {
         "tracer_bit_invisible": _bit_invisible(traced, untraced),
         "spans_reconciled": reconciled,
@@ -97,7 +95,6 @@ def headline(iters: int = 16) -> dict:
         "n_batch_spans": sum(1 for r in tracer.roots()
                              if r.name == "batch"),
         "n_metric_keys": len(snap),
-        "modelled_vs_measured_points": gap_points,
     }
 
 
@@ -133,9 +130,7 @@ def main():
     row("trace/valid_chrome_json", 0.0,
         f"{out['n_trace_events']} events, "
         f"{out['n_trace_problems']} problems")
-    row("trace/metrics", 0.0,
-        f"{out['n_metric_keys']} keys, "
-        f"{out['modelled_vs_measured_points']} gap points")
+    row("trace/metrics", 0.0, f"{out['n_metric_keys']} keys")
 
 
 if __name__ == "__main__":

@@ -55,8 +55,7 @@ def data_plane_stage_split(iters: int = 16) -> dict:
     from the metrics registry of a traced run.  The split is exactly what
     the pricing charged (the counters are incremented with the same floats
     the batches carry), so ``prep == sample + gather + feedback`` holds to
-    float eps, and the ``modelled_vs_measured`` series bounds how far the
-    model's virtual clock sits from the simulation's wall clock."""
+    float eps."""
     import numpy as np
 
     from repro.core import GIDSDataLoader, LoaderConfig
@@ -80,10 +79,6 @@ def data_plane_stage_split(iters: int = 16) -> dict:
     out["n_batches"] = n
     out["split_residual_s"] = stages["prep"] - (
         stages["sample"] + stages["gather"] + stages["feedback"])
-    gaps = [p["gap_s"]
-            for name in m.names() if name.startswith("modelled_vs_measured.")
-            for p in m.series(name).points]
-    out["max_abs_model_gap_s"] = max((abs(x) for x in gaps), default=0.0)
     return out
 
 
@@ -95,8 +90,7 @@ def main():
         row(f"roofline_dataplane_{stage}", sp[f"{stage}_s"] / n * 1e6,
             f"share={share:.3f}")
     row("roofline_dataplane_prep", sp["prep_s"] / n * 1e6,
-        f"residual={sp['split_residual_s']:.3e}s_"
-        f"model_gap={sp['max_abs_model_gap_s']:.3e}s")
+        f"residual={sp['split_residual_s']:.3e}s")
 
     recs = load_records()
     OUT.mkdir(parents=True, exist_ok=True)

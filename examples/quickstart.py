@@ -47,9 +47,8 @@ gap.
 The last section turns on the observability plane (src/repro/obs/): the
 same distributed loader runs 8 batches with a live `Tracer`, exports a
 Perfetto-loadable Chrome trace (trace.json), and prints the top-3 spans
-by priced time plus the modelled-vs-measured gap per pipeline stage from
-the `MetricsRegistry` — with tracing guaranteed bit-invisible to every
-number printed above.
+by priced time — with tracing guaranteed bit-invisible to every number
+printed above.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -357,14 +356,3 @@ for name, dur, args in spans[:3]:
     tags = " ".join(f"{k}={v}" for k, v in sorted(args.items())
                     if isinstance(v, (int, str)))
     print(f"    {name:14s} {dur*1e6:8.2f} us  {tags}")
-print("  modelled vs measured, per stage (virtual clock vs wall clock):")
-m = tracer.metrics
-for name in m.names():
-    if not name.startswith("modelled_vs_measured."):
-        continue
-    pts = m.series(name).points
-    modelled = sum(p["modelled_s"] for p in pts)
-    measured = sum(p["measured_s"] for p in pts)
-    print(f"    {name.split('.', 1)[1]:14s} modelled {modelled*1e6:8.2f} us"
-          f" | simulated in {measured*1e6:8.2f} us wall"
-          f" ({len(pts)} spans)")

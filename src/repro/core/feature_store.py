@@ -51,7 +51,14 @@ class GatherReport:
     On a multi-host plane (core/hosts.py) `remote_rows` additionally splits
     out, per SERVING shard, the storage rows requested by a different host
     — the traffic that rides each host's link in
-    `StorageTimeline.price_host_burst`.  Empty everywhere else."""
+    `StorageTimeline.price_host_burst`.  Empty everywhere else.
+
+    `h2d_bytes` / `d2h_bytes` are the bytes the gather copied to and from
+    the device: a device-store tier's probe, padded bucket and all; 0 on a
+    plane without one.  A merged window probes once, so its window report
+    and its first batch's report carry the window's bytes and the other
+    batches 0.  Report equality ignores them: it is about the tier
+    split."""
 
     n_requests: int
     bytes_per_row: int
@@ -61,6 +68,8 @@ class GatherReport:
     n_shards: int = 1
     shard_rows: tuple[int, ...] = ()
     remote_rows: tuple[int, ...] = ()
+    h2d_bytes: int = dataclasses.field(default=0, compare=False)
+    d2h_bytes: int = dataclasses.field(default=0, compare=False)
 
     def _class_count(self, latency_class: str) -> int:
         return sum(n for c, n in zip(self.tier_classes, self.tier_counts)
@@ -93,7 +102,8 @@ class GatherReport:
                                        / len(self.shard_rows))
 
     @classmethod
-    def from_plan(cls, plan: GatherPlan, bytes_per_row: int) -> "GatherReport":
+    def from_plan(cls, plan: GatherPlan, bytes_per_row: int,
+                  h2d_bytes: int = 0, d2h_bytes: int = 0) -> "GatherReport":
         ns = plan.n_shards
         shard_rows, remote_rows = (), ()
         if ns > 1:
@@ -107,6 +117,7 @@ class GatherReport:
             tier_classes=tuple(t.latency_class for t in plan.tiers),
             tier_counts=tuple(int(c) for c in plan.counts()),
             n_shards=ns, shard_rows=shard_rows, remote_rows=remote_rows,
+            h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes,
         )
 
 
@@ -230,17 +241,23 @@ class TieredFeatureStore:
     def plan(self, node_ids: np.ndarray) -> GatherPlan:
         return build_plan(self.tiers, node_ids)
 
+    def _fetched_rows(self, plan: GatherPlan, node_ids: np.ndarray):
+        """The plan's rows and the (H2D, D2H) bytes that fetched them.  A
+        device-store tier at the top already gathered them on device during
+        its probe — don't fetch them from the backstop a second time."""
+        top = plan.tiers[0]
+        rows = getattr(top, "last_rows", None)
+        if rows is None or len(rows) != len(node_ids):
+            return np.asarray(self.features[node_ids]), 0, 0
+        return rows, top.last_h2d_bytes, top.last_d2h_bytes
+
     def gather(self, node_ids: np.ndarray) -> tuple[np.ndarray, GatherReport]:
         """Fetch feature rows for (deduplicated) node_ids through the tiers."""
         plan = self.plan(node_ids)
-        # a device-store tier at the top already gathered this batch's rows
-        # on device during its probe — don't fetch them from the backstop
-        # a second time
-        rows = getattr(plan.tiers[0], "last_rows", None)
-        if rows is None or len(rows) != len(node_ids):
-            rows = np.asarray(self.features[node_ids])
+        rows, h2d, d2h = self._fetched_rows(plan, node_ids)
         report = GatherReport.from_plan(
-            plan, bytes_per_row=self.feature_dim * self.itemsize)
+            plan, bytes_per_row=self.feature_dim * self.itemsize,
+            h2d_bytes=h2d, d2h_bytes=d2h)
         self.last_plan = plan
         return rows, report
 
@@ -265,9 +282,7 @@ class TieredFeatureStore:
         unique = merged.unique_nodes
         plan = build_plan_merged(self.tiers, unique,
                                  merged.batch_multiplicity())
-        rows = getattr(plan.tiers[0], "last_rows", None)
-        if rows is None or len(rows) != len(unique):
-            rows = np.asarray(self.features[unique])
+        rows, h2d, d2h = self._fetched_rows(plan, unique)
         bytes_per_row = self.feature_dim * self.itemsize
 
         storage_mask = plan.storage_mask()
@@ -321,7 +336,7 @@ class TieredFeatureStore:
             n_requests=merged.n_unique,
             tier_counts=tuple(int(c) for c in plan.counts()),
             shard_rows=shard_rows, remote_rows=remote_rows,
-            **tier_meta, **window_stats)
+            h2d_bytes=h2d, d2h_bytes=d2h, **tier_meta, **window_stats)
 
         rows_list, reports = [], []
         for i in range(merged.n_batches):
@@ -342,6 +357,8 @@ class TieredFeatureStore:
                 n_requests=len(inv),
                 tier_counts=tuple(int(c) for c in counts),
                 shard_rows=batch_shard_rows, remote_rows=batch_remote_rows,
+                h2d_bytes=h2d if i == 0 else 0,
+                d2h_bytes=d2h if i == 0 else 0,
                 **tier_meta, **window_stats))
         self.last_plan = plan
         return rows_list, reports, window_report

@@ -227,11 +227,13 @@ del LoaderConfig.mode
 class BatchPlan:
     """Stage-1 output: what to gather, plus the resume point.  `snapshot` is
     the sampler state *before* this batch was sampled, so a checkpoint taken
-    while the batch is staged-but-unconsumed replays it deterministically."""
+    while the batch is staged-but-unconsumed replays it deterministically.
+    `index` counts the loader's plans; it tags the batch's wall spans."""
 
     blocks: SampledBlocks
     merge_depth: int
     snapshot: dict
+    index: int
 
 
 @dataclasses.dataclass
@@ -389,9 +391,12 @@ class GIDSDataLoader:
         # pricing, so features, blocks, and every priced float are
         # bit-identical either way.
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        self._batch_index = 0
         self._window_index = 0
+        self._plans = 0
         if self.tracer.enabled:
+            for tier in self.store.tiers:
+                if hasattr(tier, "tracer"):
+                    tier.tracer = self.tracer
             self.timeline.metrics = self.tracer.metrics
             if self.topo is not None:
                 self.topo.timeline.metrics = self.tracer.metrics
@@ -467,34 +472,38 @@ class GIDSDataLoader:
     def plan_next(self) -> BatchPlan:
         """Stage 1: sampling + admit-side staging.  Refills the lookahead
         (sampling ahead, window admits), pops the next batch's blocks."""
-        with self.tracer.stage("plan_next") as sp:
+        index = self._plans
+        self._plans += 1
+        with self.tracer.stage("plan_next", batch=index):
             depth = self._refill_lookahead()
             snap, blocks = self._lookahead.popleft()
             self._win_idx = max(0, self._win_idx - 1)
             self._requests_per_iter = blocks.num_requests
-            sp.modelled(float(getattr(blocks, "sample_time_s", 0.0)))
-        return BatchPlan(blocks=blocks, merge_depth=depth, snapshot=snap)
+        return BatchPlan(blocks=blocks, merge_depth=depth, snapshot=snap,
+                         index=index)
 
     def execute(self, plan: BatchPlan) -> Batch:
         """Stage 2: data movement + pricing.  Folds the tier stack over the
         plan's nodes, gathers the rows, prices the tier split."""
         blocks = plan.blocks
-        with self.tracer.stage("execute") as sp:
+        with self.tracer.stage("execute", batch=plan.index):
             rows, report = self.store.gather(blocks.all_nodes)
-            self.accumulator.update(report.n_requests, report.redirected)
-
-            outstanding = self.accumulator.outstanding(blocks.num_requests)
-            prev_burst = self.timeline.shard_burst
-            gather_s = self.plane.price(self.timeline, report, outstanding)
-            charge = self._feedback_step(blocks.all_nodes, None)
-            t = gather_s + charge
-            # a topology plane priced the sampling stage when the blocks were
-            # drawn (plan_next); prep now covers the full Fig. 1 path
-            sample_s = float(getattr(blocks, "sample_time_s", 0.0))
-            sp.modelled(t + sample_s)
-            if self.tracer.enabled:
-                self._trace_batch(blocks, report, gather_s, charge,
-                                  t + sample_s, prev_burst)
+            with self.tracer.stage("price"):
+                self.accumulator.update(report.n_requests, report.redirected)
+                outstanding = self.accumulator.outstanding(
+                    blocks.num_requests)
+                prev_burst = self.timeline.shard_burst
+                gather_s = self.plane.price(self.timeline, report,
+                                            outstanding)
+                charge = self._feedback_step(blocks.all_nodes, None)
+                t = gather_s + charge
+                # a topology plane priced the sampling stage when the blocks
+                # were drawn (plan_next); prep now covers the full Fig. 1
+                # path
+                sample_s = float(getattr(blocks, "sample_time_s", 0.0))
+                if self.tracer.enabled:
+                    self._trace_batch(plan.index, blocks, report, gather_s,
+                                      charge, t + sample_s, prev_burst)
         return Batch(blocks=blocks, features=rows, report=report,
                      prep_time_s=t + sample_s, merge_depth=plan.merge_depth,
                      sample_time_s=sample_s)
@@ -522,25 +531,31 @@ class GIDSDataLoader:
 
     # -- span-tree construction (enabled tracer only) --------------------------
     def _trace_hops(self, root, blocks) -> None:
-        for r in getattr(blocks, "hop_reports", ()):
+        """One `sample` child holding the per-hop spans.  Its duration is
+        `sample_time_s` itself, so the root's children add up in the same
+        grouping as the prep time (`share + sample_time_s`)."""
+        reports = getattr(blocks, "hop_reports", ())
+        if not reports:
+            return
+        sample = root.child("sample", float(blocks.sample_time_s),
+                            cat="sample")
+        for r in reports:
             hbm, host, sto = r.pages_by_tier
-            root.child(f"sample/hop{r.hop}", float(r.time_s), cat="sample",
-                       edge_reads=r.n_edge_reads, frontier=r.n_frontier,
-                       pages_hbm=hbm, pages_host=host, pages_storage=sto)
+            sample.child(f"sample/hop{r.hop}", float(r.time_s), cat="sample",
+                         edge_reads=r.n_edge_reads, frontier=r.n_frontier,
+                         pages_hbm=hbm, pages_host=host, pages_storage=sto)
 
-    def _trace_batch(self, blocks, report, gather_s: float, charge: float,
-                     prep_s: float, prev_burst, window: int | None = None
-                     ) -> None:
-        """One per-batch virtual span tree: root duration is exactly
+    def _trace_batch(self, index: int, blocks, report, gather_s: float,
+                     charge: float, prep_s: float, prev_burst) -> None:
+        """One per-batch virtual span tree, tagged with the plan's `index`
+        like the batch's wall stages: root duration is exactly
         `Batch.prep_time_s`, sequential children partition it into the
         per-hop sampling, the priced gather, and any feedback charge;
         per-shard/per-host drains (and fault recovery sub-events) overlay
         the gather span on their own tracks."""
         tr = self.tracer
-        args = {"index": self._batch_index, "requests": report.n_requests}
-        if window is not None:
-            args["window"] = window
-        root = tr.batch("batch", track="pipeline", **args)
+        root = tr.batch("batch", track="pipeline", index=index,
+                        requests=report.n_requests)
         self._trace_hops(root, blocks)
         g = root.child("gather", float(gather_s), cat="gather",
                        n_storage=report.n_storage,
@@ -552,7 +567,6 @@ class GIDSDataLoader:
             root.child("feedback", float(charge), cat="feedback")
         root.close(float(prep_s))
         self._record_batch_metrics(blocks, gather_s, charge, prep_s)
-        self._batch_index += 1
 
     def _trace_window(self, plans, window_report, gather_s: float,
                       charge: float, burst_s: float, prev_burst) -> None:
@@ -587,15 +601,13 @@ class GIDSDataLoader:
         prep = burst_s / len(plans)
         for p in plans:
             sample_s = float(getattr(p.blocks, "sample_time_s", 0.0))
-            root = tr.batch("batch", track="pipeline",
-                            index=self._batch_index,
+            root = tr.batch("batch", track="pipeline", index=p.index,
                             window=self._window_index)
             self._trace_hops(root, p.blocks)
             root.child("gather_share", float(prep), cat="gather",
                        window=self._window_index)
             root.close(float(prep + sample_s))
             self._record_batch_metrics(p.blocks, prep, 0.0, prep + sample_s)
-            self._batch_index += 1
         self._window_index += 1
 
     def _record_batch_metrics(self, blocks, gather_s: float, charge: float,
@@ -636,7 +648,8 @@ class GIDSDataLoader:
         Features are bit-identical to `execute()` run per plan; the reports
         (tier telemetry) and modelled times differ — that difference IS the
         modelled speedup of the §3.2 merge."""
-        with self.tracer.stage("execute_window", n_plans=len(plans)) as sp:
+        with self.tracer.stage("execute_window", batch=plans[0].index,
+                               n_plans=len(plans)):
             merged = self.accumulator.merge(
                 [p.blocks.all_nodes for p in plans])
             # retire the consumed window entries and stage the NEXT window's
@@ -647,23 +660,24 @@ class GIDSDataLoader:
             self._sync_window()
             rows_list, reports, window_report = \
                 self.store.gather_merged(merged)
-            # one telemetry update per window: the merged burst's unique
-            # split (what actually reached storage), not per-batch raw counts
-            self.accumulator.update(window_report.n_requests,
-                                    window_report.redirected)
-            prev_burst = self.timeline.shard_burst
-            gather_s = self.timeline.price_merged_burst(window_report)
-            # the window is one priced burst, so it is one feedback tick:
-            # the unique request set (with window multiplicity) is what the
-            # plane measured, and any migration charge amortizes across the
-            # window's batches exactly like the burst itself
-            charge = self._feedback_step(merged.unique_nodes,
-                                         merged.batch_multiplicity())
-            burst_s = gather_s + charge
-            sp.modelled(burst_s)
-            if self.tracer.enabled:
-                self._trace_window(plans, window_report, gather_s, charge,
-                                   burst_s, prev_burst)
+            with self.tracer.stage("price"):
+                # one telemetry update per window: the merged burst's unique
+                # split (what actually reached storage), not per-batch raw
+                # counts
+                self.accumulator.update(window_report.n_requests,
+                                        window_report.redirected)
+                prev_burst = self.timeline.shard_burst
+                gather_s = self.timeline.price_merged_burst(window_report)
+                # the window is one priced burst, so it is one feedback
+                # tick: the unique request set (with window multiplicity) is
+                # what the plane measured, and any migration charge
+                # amortizes across the window's batches like the burst
+                charge = self._feedback_step(merged.unique_nodes,
+                                             merged.batch_multiplicity())
+                burst_s = gather_s + charge
+                if self.tracer.enabled:
+                    self._trace_window(plans, window_report, gather_s,
+                                       charge, burst_s, prev_burst)
             prep = burst_s / len(plans)
             # each batch's own priced sampling time rides on top of its
             # amortized share of the window's feature burst

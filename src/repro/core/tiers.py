@@ -43,6 +43,8 @@ from typing import Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
+from repro.obs import NULL_TRACER
+
 from .constant_buffer import ConstantBuffer
 from .software_cache import WindowBufferedCache
 from .storage_sim import IO_BYTES
@@ -150,10 +152,16 @@ class DeviceStoreTier(_TierBase):
 
     Requests are padded to a power-of-two bucket so the jitted step re-uses
     compiled shapes across batches.  `last_rows` holds the device-gathered
-    rows of the most recent probe (the real data path of this tier).
+    rows of the most recent probe (the real data path of this tier), and
+    `last_h2d_bytes` / `last_d2h_bytes` the bytes that probe copied to and
+    from the device: the padded ids, staged rows and reuse counts up, the
+    padded rows and hit mask back.  A probe runs as the wall stages
+    `stage_rows`, `h2d`, `device_wait` and `d2h` of `tracer`, which the
+    loader sets when its own tracer is enabled.
     """
 
     latency_class = "hbm"
+    tracer = NULL_TRACER
 
     def __init__(self, features: np.ndarray, num_lines: int, ways: int = 8,
                  window_depth: int = 0, use_pallas: bool = True,
@@ -171,6 +179,7 @@ class DeviceStoreTier(_TierBase):
         self.use_pallas = use_pallas
         self.name = name
         self.last_rows = None
+        self.last_h2d_bytes = self.last_d2h_bytes = 0
 
     @property
     def capacity_bytes(self) -> int:
@@ -206,17 +215,26 @@ class DeviceStoreTier(_TierBase):
         return self._probe_rows(node_ids)
 
     def _probe_rows(self, node_ids: np.ndarray) -> np.ndarray:
-        n = len(node_ids)
-        ids = self._mod.bucket_rows(np.asarray(node_ids, np.int32), -1)
-        pad = len(ids)
-        staged = self._host_features[np.maximum(ids, 0)]
-        fc = np.zeros(pad, np.int32)
-        fc[:n] = self._future_counts(node_ids)
-        self.store, rows, hits = self._mod.device_gather(
-            self.store, self._jnp.asarray(ids), self._jnp.asarray(staged),
-            self._jnp.asarray(fc), use_pallas=self.use_pallas)
-        self.last_rows = np.asarray(rows)[:n]
-        return np.asarray(hits)[:n]
+        n, tr, jnp = len(node_ids), self.tracer, self._jnp
+        with tr.stage("stage_rows"):
+            ids = self._mod.bucket_rows(np.asarray(node_ids, np.int32), -1)
+            staged = self._host_features[np.maximum(ids, 0)]
+            fc = np.zeros(len(ids), np.int32)
+            fc[:n] = self._future_counts(node_ids)
+        with tr.stage("h2d"):
+            ids_d, staged_d, fc_d = (jnp.asarray(ids), jnp.asarray(staged),
+                                     jnp.asarray(fc))
+        with tr.stage("device_wait"):
+            self.store, rows, hits = self._mod.device_gather(
+                self.store, ids_d, staged_d, fc_d, use_pallas=self.use_pallas)
+            rows.block_until_ready()
+            hits.block_until_ready()
+        with tr.stage("d2h"):
+            self.last_rows = np.asarray(rows)[:n]
+            hit_mask = np.asarray(hits)[:n]
+        self.last_h2d_bytes = ids.nbytes + staged.nbytes + fc.nbytes
+        self.last_d2h_bytes = rows.nbytes + hits.nbytes
+        return hit_mask
 
     def admit(self, node_ids: np.ndarray) -> None:
         if self.window_depth == 0:
@@ -247,6 +265,7 @@ class DeviceStoreTier(_TierBase):
         self.store = self._mod.init_store(*self._init_args)
         self.window.clear()
         self.last_rows = None
+        self.last_h2d_bytes = self.last_d2h_bytes = 0
 
 
 class TenantCacheTier(_TierBase):

@@ -7,14 +7,14 @@ attributes; ``validate_trace`` is the CI schema gate.  Everything
 defaults to :data:`NULL_TRACER`, which is bit- and price-invisible.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               NULL_METRICS, NullMetrics, Series)
+                               NULL_METRICS, NullMetrics)
 from repro.obs.trace import (NULL_SPAN, NULL_TRACER, NullTracer, Span,
                              Tracer, attach_burst_spans)
 from repro.obs.validate import validate_events, validate_trace, validate_tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL_METRICS",
-    "NullMetrics", "Series",
+    "NullMetrics",
     "NULL_SPAN", "NULL_TRACER", "NullTracer", "Span", "Tracer",
     "attach_burst_spans",
     "validate_events", "validate_trace", "validate_tracer",

@@ -1,7 +1,7 @@
 """Metrics registry for the observability plane.
 
 A :class:`MetricsRegistry` is a flat namespace of typed instruments —
-counters, gauges, histograms, and series — that replaces the scattered
+counters, gauges and histograms — that replaces the scattered
 ``last_*`` attributes the data plane used to grow per subsystem.  Call
 sites get-or-create instruments by name (``registry.counter("x").inc()``),
 so instrumentation never has to pre-declare anything, and
@@ -79,30 +79,6 @@ class Histogram:
                 "min": self.min, "max": self.max}
 
 
-class Series:
-    """Append-only sequence of points (dicts or scalars), kept in order.
-
-    Used for the first-class ``modelled_vs_measured`` gap series: one
-    point per traced stage invocation, carrying both clocks.
-    """
-
-    __slots__ = ("name", "points")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.points: list[Any] = []
-
-    def append(self, point: Any) -> None:
-        self.points.append(point)
-
-    def __len__(self) -> int:
-        return len(self.points)
-
-    def snapshot(self) -> dict[str, Any]:
-        return {"type": "series", "n": len(self.points),
-                "points": list(self.points)}
-
-
 class MetricsRegistry:
     """Get-or-create namespace of instruments, snapshot-able as JSON."""
 
@@ -127,9 +103,6 @@ class MetricsRegistry:
 
     def histogram(self, name: str) -> Histogram:
         return self._get(name, Histogram)
-
-    def series(self, name: str) -> Series:
-        return self._get(name, Series)
 
     def get(self, name: str):
         return self._instruments.get(name)
@@ -156,7 +129,6 @@ class _NullInstrument:
     mean = 0.0
     min = None
     max = None
-    points: list = []
 
     def inc(self, amount: float = 1.0) -> None:
         pass
@@ -166,12 +138,6 @@ class _NullInstrument:
 
     def observe(self, value: float) -> None:
         pass
-
-    def append(self, point: Any) -> None:
-        pass
-
-    def __len__(self) -> int:
-        return 0
 
     def snapshot(self) -> dict[str, Any]:
         return {"type": "null"}

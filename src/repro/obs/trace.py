@@ -14,10 +14,13 @@ long the Python simulation itself takes.  The tracer records both:
   start are laid out lazily at export time on per-track cursors, so the
   hot path only stores durations.
 * **Wall spans** come from ``tracer.stage(name)`` context managers that
-  measure ``time.perf_counter`` around a pipeline stage; attaching the
-  priced duration via ``handle.modelled(dur_s)`` records a point in the
-  ``modelled_vs_measured.<stage>`` series of the registry — the gap the
-  ROADMAP wants as a tracked number.
+  measure ``time.perf_counter`` around a pipeline stage.  Stages nest: each
+  wall span carries its own id, its enclosing stage's id (``None`` at top
+  level) and the loader's batch index, which a top-level stage is given
+  and nested stages inherit, so a stage's self time is its duration minus
+  its children's.  Each stage is also a ``jax.profiler.TraceAnnotation``
+  of the same name, so under the JAX profiler the program's stages land
+  in the host plane on the device trace's clock.
 
 Export is Chrome trace-event JSON (the ``traceEvents`` array form), which
 Perfetto loads directly: virtual time on pid 1, wall time on pid 2, one
@@ -31,9 +34,10 @@ are bit-identical either way.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import time
-from contextlib import contextmanager
 from typing import Any, Iterator
 
 from repro.obs.metrics import MetricsRegistry, NULL_METRICS
@@ -71,7 +75,8 @@ class Span:
     """One node of a trace tree; durations in (virtual or wall) seconds."""
 
     __slots__ = ("name", "cat", "kind", "track", "t0", "dur",
-                 "wall_t0", "wall_dur", "parallel", "args", "children")
+                 "wall_t0", "wall_dur", "parallel", "args", "children",
+                 "sid", "parent", "batch")
 
     def __init__(self, name: str, *, cat: str = "stage", kind: str = SPAN,
                  track: str | None = None, t0: float | None = None,
@@ -88,6 +93,10 @@ class Span:
         self.parallel = parallel
         self.args = args or {}
         self.children: list[Span] = []
+        # wall spans only: own id, enclosing stage's id, loader batch index
+        self.sid: int | None = None
+        self.parent: int | None = None
+        self.batch: int | None = None
 
     # -- building ---------------------------------------------------------
     def child(self, name: str, dur: float = 0.0, *, cat: str = "stage",
@@ -113,11 +122,6 @@ class Span:
 
     def annotate(self, **args) -> "Span":
         self.args.update(args)
-        return self
-
-    def modelled(self, dur_s: float) -> "Span":
-        """Attach the priced duration to a wall-clock stage span."""
-        self.dur = float(dur_s)
         return self
 
     # -- reconciliation ---------------------------------------------------
@@ -160,9 +164,6 @@ class _NullSpan:
     def annotate(self, **kw):
         return self
 
-    def modelled(self, dur_s):
-        return self
-
     def __enter__(self):
         return self
 
@@ -182,6 +183,8 @@ class Tracer:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._events: list[Span] = []      # top-level virtual spans/instants
         self._wall: list[Span] = []        # closed wall stage spans
+        self._open: list[Span] = []        # wall stages open, outermost first
+        self._next_sid = 0
         self._laid_out = False
 
     # -- building ---------------------------------------------------------
@@ -201,24 +204,30 @@ class Tracer:
         self._laid_out = False
         return sp
 
-    @contextmanager
-    def stage(self, name: str, *, track: str = "loop", cat: str = "stage",
-              **args):
-        """Wall-clock a pipeline stage; ``handle.modelled(s)`` records the
-        modelled-vs-measured gap for this stage into the registry."""
+    @contextlib.contextmanager
+    def stage(self, name: str, *, batch: int | None = None,
+              track: str = "loop", cat: str = "stage", **args):
+        """Wall-clock a pipeline stage, nested in the innermost open one.
+        `batch` tags a top-level stage with the loader's batch index;
+        nested stages inherit their parent's.  The same interval is a
+        `jax.profiler.TraceAnnotation` named `name`."""
         sp = Span(name, cat=cat, kind=WALL, track=track, args=args)
-        sp.wall_t0 = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            sp.wall_dur = time.perf_counter() - sp.wall_t0
-            self._wall.append(sp)
-            if sp.dur is not None:
-                self.metrics.series(f"modelled_vs_measured.{name}").append({
-                    "modelled_s": sp.dur,
-                    "measured_s": sp.wall_dur,
-                    "gap_s": sp.wall_dur - sp.dur,
-                })
+        sp.sid = self._next_sid
+        self._next_sid += 1
+        if self._open:
+            sp.parent = self._open[-1].sid
+            sp.batch = self._open[-1].batch
+        else:
+            sp.batch = batch
+        self._open.append(sp)
+        with _annotation_type()(name):
+            sp.wall_t0 = time.perf_counter()
+            try:
+                yield sp
+            finally:
+                sp.wall_dur = time.perf_counter() - sp.wall_t0
+                self._open.pop()
+                self._wall.append(sp)
 
     def reset(self) -> None:
         self._events.clear()
@@ -324,9 +333,7 @@ class Tracer:
         base = min((w.wall_t0 for w in self._wall), default=0.0)
         for w in self._wall:
             args = {k: _jsonify(v) for k, v in w.args.items()}
-            if w.dur is not None:
-                args["modelled_s"] = w.dur
-                args["gap_s"] = w.wall_dur - w.dur
+            args.update(id=w.sid, parent=w.parent, batch=w.batch)
             events.append({"name": w.name, "cat": w.cat, "ph": "X",
                            "pid": PID_WALL,
                            "tid": tid_for(PID_WALL, w.track or "loop"),
@@ -371,6 +378,17 @@ class NullTracer(Tracer):
 
 
 NULL_TRACER = NullTracer()
+
+
+@functools.cache
+def _annotation_type():
+    """`jax.profiler.TraceAnnotation`, or a null context where jax is not
+    installed (the obs plane itself needs only numpy)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return contextlib.nullcontext
+    return TraceAnnotation
 
 
 def attach_burst_spans(parent: Span, burst: Any) -> None:

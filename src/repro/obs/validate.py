@@ -9,6 +9,8 @@ Checks two layers:
   its parent's interval, sequential siblings do not run backwards, and
   top-level spans on each track have monotone (non-decreasing) start
   times — serve requests may overlap while queued, but never regress.
+  A nested wall stage lies inside its enclosing stage and shares its
+  batch index.
 
 Both return a list of problem strings; empty means valid.
 """
@@ -106,9 +108,21 @@ def validate_tracer(tracer: Tracer) -> list[str]:
         last_start[track] = max(last_start.get(track, 0.0), ev.t0)
         if ev.kind == SPAN:
             _check_tree(ev, problems, ev.name)
+    by_sid = {w.sid: w for w in tracer._wall}
     for w in tracer._wall:
         if w.wall_t0 is None or w.wall_dur is None or w.wall_dur < 0:
             problems.append(f"{w.name}: wall span not closed")
+            continue
+        p = by_sid.get(w.parent)
+        if p is None or p.wall_dur is None:
+            continue
+        if (w.wall_t0 < p.wall_t0 - _EPS
+                or w.wall_t0 + w.wall_dur > p.wall_t0 + p.wall_dur + _EPS):
+            problems.append(f"{w.name}: wall span escapes its parent "
+                            f"{p.name} (id {p.sid})")
+        if w.batch != p.batch:
+            problems.append(f"{w.name}: batch {w.batch} differs from its "
+                            f"parent {p.name}'s {p.batch}")
     return problems
 
 

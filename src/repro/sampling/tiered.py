@@ -42,9 +42,9 @@ def tiered_sample_blocks(graph: CSRGraph, topo: TieredTopologyStore,
                          seeds: np.ndarray, fanouts: Sequence[int],
                          rng: np.random.Generator,
                          tracer=None) -> TieredSampledBlocks:
-    """`tracer` (repro.obs) wall-clocks the whole sampling sweep and
-    attaches the summed priced hop time — observation only, the sampled
-    blocks and the per-hop reports are identical with or without it."""
+    """`tracer` (repro.obs) wall-clocks the whole sampling sweep —
+    observation only, the sampled blocks and the per-hop reports are
+    identical with or without it."""
     if tracer is None:
         from repro.obs import NULL_TRACER as tracer  # noqa: N811
     reports: list[TopologyGatherReport] = []
@@ -56,11 +56,10 @@ def tiered_sample_blocks(graph: CSRGraph, topo: TieredTopologyStore,
         reports.append(topo.hop_report(read_pos, hop=hop,
                                        n_frontier=n_frontier))
 
-    with tracer.stage("sample", cat="sample", seeds=len(seeds)) as sp:
+    with tracer.stage("sample", cat="sample", seeds=len(seeds)):
         hop_nodes, all_nodes, n_req = run_sample_hops(graph, seeds, fanouts,
                                                       rng, hop_cb=price_hop)
         sample_time_s = float(sum(r.time_s for r in reports))
-        sp.modelled(sample_time_s)
     return TieredSampledBlocks(
         seeds=seeds, hop_nodes=hop_nodes, all_nodes=all_nodes,
         num_requests=n_req, hop_reports=reports,

@@ -504,13 +504,12 @@ class GNNServeEngine:
     def run(self, requests: Sequence[ServeRequest]) -> ServeResult:
         """Serve an arrival-time-stamped stream to completion — see `_run`
         for the scheduling rules.  With an enabled tracer the run is
-        wall-clocked as one ``serve_run`` stage (modelled time = the priced
-        makespan), every retired request gets a virtual span on its
-        tenant's track, and the serve counters land in the registry."""
+        wall-clocked as one ``serve_run`` stage, every retired request gets
+        a virtual span on its tenant's track, and the serve counters land
+        in the registry."""
         with self.tracer.stage("serve_run", cat="serve",
-                               n_requests=len(requests)) as sp:
+                               n_requests=len(requests)):
             result = self._run(requests)
-            sp.modelled(result.makespan_s)
         if self.tracer.enabled:
             self._trace_requests(result)
             self._record_serve_metrics(result)

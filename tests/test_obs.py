@@ -40,14 +40,12 @@ def test_registry_instruments():
     h = m.histogram("h")
     for v in (1.0, 2.0, 3.0):
         h.observe(v)
-    m.series("s").append({"x": 1})
     assert m.counter("a").value == 3.5
     assert m.gauge("g").value == 4.0
     assert h.count == 3 and h.mean == 2.0 and h.min == 1.0 and h.max == 3.0
     snap = m.snapshot()
     assert snap["a"]["type"] == "counter" and snap["a"]["value"] == 3.5
     assert snap["h"]["count"] == 3
-    assert snap["s"]["points"] == [{"x": 1}]
     json.dumps(snap)   # snapshot must be JSON-serializable as-is
     m.reset()
     assert m.snapshot() == {}
@@ -100,8 +98,8 @@ def test_chrome_export_schema():
     root.child("gather", 1.0, rows=np.int64(7))
     root.close()
     tr.instant("migration", cost_s=0.25)
-    with tr.stage("plan_next") as sp:
-        sp.modelled(1.0)
+    with tr.stage("plan_next", batch=0):
+        pass
     events = tr.chrome_events()
     assert validate_events(events) == []
     by_ph = {}
@@ -133,22 +131,12 @@ def test_validate_catches_escaping_child():
     assert any("escapes" in p for p in validate_tracer(tr))
 
 
-def test_modelled_vs_measured_series():
-    tr = Tracer()
-    with tr.stage("execute") as sp:
-        sp.modelled(0.25)
-    pts = tr.metrics.series("modelled_vs_measured.execute").points
-    assert len(pts) == 1
-    p = pts[0]
-    assert p["modelled_s"] == 0.25 and p["measured_s"] >= 0.0
-    assert p["gap_s"] == p["measured_s"] - p["modelled_s"]
-
-
 def test_null_tracer_records_nothing():
     s = NULL_TRACER.batch("b")
     assert s.child("x", 1.0) is s
-    with NULL_TRACER.stage("s") as sp:
-        sp.modelled(1.0)
+    with NULL_TRACER.stage("s", batch=0) as sp:
+        assert sp is s
+    assert NULL_TRACER.wall_spans() == []
     assert NULL_TRACER.chrome_events() == []
     assert NULL_TRACER.metrics.snapshot() == {}
 
@@ -245,7 +233,9 @@ def test_trace_covers_pipeline_stages(graph_and_feats):
     snap = tr.metrics.snapshot()
     assert snap["pipeline.batches"]["value"] >= 6.0
     assert "topo.hops" in snap and "topo.edge_reads" in snap
-    assert any(k.startswith("modelled_vs_measured.") for k in snap)
+    by_sid = {w.sid: w for w in dl.tracer.wall_spans()}
+    assert all(by_sid[w.parent].name == "plan_next"
+               for w in dl.tracer.wall_spans() if w.name == "sample")
     assert any(k.startswith("tier.") and k.endswith("hit_ratio")
                for k in snap)
 

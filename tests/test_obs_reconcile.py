@@ -135,21 +135,6 @@ def test_serve_window_spans_match_window_traces():
         assert sp.reconcile_error() <= EPS
 
 
-def test_modelled_vs_measured_gap_recorded_per_stage(graph_and_feats):
-    g, feats = graph_and_feats
-    tr, _ = _run_traced(g, feats, "gids-topo-merged")
-    snap = tr.metrics.snapshot()
-    gaps = {k: v for k, v in snap.items()
-            if k.startswith("modelled_vs_measured.")}
-    assert {"modelled_vs_measured.plan_next",
-            "modelled_vs_measured.execute_window",
-            "modelled_vs_measured.sample"} <= set(gaps)
-    for series in gaps.values():
-        for p in series["points"]:
-            assert p["gap_s"] == p["measured_s"] - p["modelled_s"]
-            assert p["measured_s"] >= 0.0
-
-
 # -- fuzzed sweep (hypothesis when installed, seeded grid otherwise) -----------
 
 try:
