@@ -50,8 +50,8 @@ def init_store(num_lines: int, dim: int, ways: int = 8,
 
 def _fills(hits: jnp.ndarray, slots: jnp.ndarray, ids: jnp.ndarray,
            num_lines: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Row-store writes of one batch, from `cache_jax.access`'s sequential
-    outcome.  Returns (fill_slots, refilled):
+    """Row-store writes of one batch, from `cache_jax.access`'s per-set
+    ordered outcome.  Returns (fill_slots, refilled):
 
     - fill_slots[i]: the line request i's staged row is written to, or
       `num_lines` (dropped).  A line filled twice keeps only its LAST

@@ -57,8 +57,9 @@ class GatherReport:
     the device: a device-store tier's probe, padded bucket and all; 0 on a
     plane without one.  A merged window probes once, so its window report
     and its first batch's report carry the window's bytes and the other
-    batches 0.  Report equality ignores them: it is about the tier
-    split."""
+    batches 0.  `access_rounds` is the device-store probe's count of
+    set-parallel cache rounds (0 without one), carried the same way.
+    Report equality ignores these three: it is about the tier split."""
 
     n_requests: int
     bytes_per_row: int
@@ -70,6 +71,7 @@ class GatherReport:
     remote_rows: tuple[int, ...] = ()
     h2d_bytes: int = dataclasses.field(default=0, compare=False)
     d2h_bytes: int = dataclasses.field(default=0, compare=False)
+    access_rounds: int = dataclasses.field(default=0, compare=False)
 
     def _class_count(self, latency_class: str) -> int:
         return sum(n for c, n in zip(self.tier_classes, self.tier_counts)
@@ -103,7 +105,8 @@ class GatherReport:
 
     @classmethod
     def from_plan(cls, plan: GatherPlan, bytes_per_row: int,
-                  h2d_bytes: int = 0, d2h_bytes: int = 0) -> "GatherReport":
+                  h2d_bytes: int = 0, d2h_bytes: int = 0,
+                  access_rounds: int = 0) -> "GatherReport":
         ns = plan.n_shards
         shard_rows, remote_rows = (), ()
         if ns > 1:
@@ -118,6 +121,7 @@ class GatherReport:
             tier_counts=tuple(int(c) for c in plan.counts()),
             n_shards=ns, shard_rows=shard_rows, remote_rows=remote_rows,
             h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes,
+            access_rounds=access_rounds,
         )
 
 
@@ -242,22 +246,24 @@ class TieredFeatureStore:
         return build_plan(self.tiers, node_ids)
 
     def _fetched_rows(self, plan: GatherPlan, node_ids: np.ndarray):
-        """The plan's rows and the (H2D, D2H) bytes that fetched them.  A
-        device-store tier at the top already gathered them on device during
-        its probe — don't fetch them from the backstop a second time."""
+        """The plan's rows, the (H2D, D2H) bytes that fetched them and the
+        probe's cache rounds.  A device-store tier at the top already
+        gathered them on device during its probe — don't fetch them from
+        the backstop a second time."""
         top = plan.tiers[0]
         rows = getattr(top, "last_rows", None)
         if rows is None or len(rows) != len(node_ids):
-            return np.asarray(self.features[node_ids]), 0, 0
-        return rows, top.last_h2d_bytes, top.last_d2h_bytes
+            return np.asarray(self.features[node_ids]), 0, 0, 0
+        return (rows, top.last_h2d_bytes, top.last_d2h_bytes,
+                top.last_access_rounds)
 
     def gather(self, node_ids: np.ndarray) -> tuple[np.ndarray, GatherReport]:
         """Fetch feature rows for (deduplicated) node_ids through the tiers."""
         plan = self.plan(node_ids)
-        rows, h2d, d2h = self._fetched_rows(plan, node_ids)
+        rows, h2d, d2h, rounds = self._fetched_rows(plan, node_ids)
         report = GatherReport.from_plan(
             plan, bytes_per_row=self.feature_dim * self.itemsize,
-            h2d_bytes=h2d, d2h_bytes=d2h)
+            h2d_bytes=h2d, d2h_bytes=d2h, access_rounds=rounds)
         self.last_plan = plan
         return rows, report
 
@@ -282,7 +288,7 @@ class TieredFeatureStore:
         unique = merged.unique_nodes
         plan = build_plan_merged(self.tiers, unique,
                                  merged.batch_multiplicity())
-        rows, h2d, d2h = self._fetched_rows(plan, unique)
+        rows, h2d, d2h, rounds = self._fetched_rows(plan, unique)
         bytes_per_row = self.feature_dim * self.itemsize
 
         storage_mask = plan.storage_mask()
@@ -336,7 +342,8 @@ class TieredFeatureStore:
             n_requests=merged.n_unique,
             tier_counts=tuple(int(c) for c in plan.counts()),
             shard_rows=shard_rows, remote_rows=remote_rows,
-            h2d_bytes=h2d, d2h_bytes=d2h, **tier_meta, **window_stats)
+            h2d_bytes=h2d, d2h_bytes=d2h, access_rounds=rounds,
+            **tier_meta, **window_stats)
 
         rows_list, reports = [], []
         for i in range(merged.n_batches):
@@ -359,6 +366,7 @@ class TieredFeatureStore:
                 shard_rows=batch_shard_rows, remote_rows=batch_remote_rows,
                 h2d_bytes=h2d if i == 0 else 0,
                 d2h_bytes=d2h if i == 0 else 0,
+                access_rounds=rounds if i == 0 else 0,
                 **tier_meta, **window_stats))
         self.last_plan = plan
         return rows_list, reports, window_report

@@ -155,9 +155,11 @@ class DeviceStoreTier(_TierBase):
     rows of the most recent probe (the real data path of this tier), and
     `last_h2d_bytes` / `last_d2h_bytes` the bytes that probe copied to and
     from the device: the padded ids, staged rows and reuse counts up, the
-    padded rows and hit mask back.  A probe runs as the wall stages
-    `stage_rows`, `h2d`, `device_wait` and `d2h` of `tracer`, which the
-    loader sets when its own tracer is enabled.
+    padded rows and hit mask back.  `last_access_rounds` is the number of
+    set-parallel rounds its cache lookup took (`CacheState.rounds`: the
+    probe's largest count of ids in one cache set).  A probe runs as the
+    wall stages `stage_rows`, `h2d`, `device_wait` and `d2h` of `tracer`,
+    which the loader sets when its own tracer is enabled.
     """
 
     latency_class = "hbm"
@@ -180,6 +182,7 @@ class DeviceStoreTier(_TierBase):
         self.name = name
         self.last_rows = None
         self.last_h2d_bytes = self.last_d2h_bytes = 0
+        self.last_access_rounds = 0
 
     @property
     def capacity_bytes(self) -> int:
@@ -232,6 +235,7 @@ class DeviceStoreTier(_TierBase):
         with tr.stage("d2h"):
             self.last_rows = np.asarray(rows)[:n]
             hit_mask = np.asarray(hits)[:n]
+            self.last_access_rounds = int(self.store.cache.rounds)
         self.last_h2d_bytes = ids.nbytes + staged.nbytes + fc.nbytes
         self.last_d2h_bytes = rows.nbytes + hits.nbytes
         return hit_mask
@@ -266,6 +270,7 @@ class DeviceStoreTier(_TierBase):
         self.window.clear()
         self.last_rows = None
         self.last_h2d_bytes = self.last_d2h_bytes = 0
+        self.last_access_rounds = 0
 
 
 class TenantCacheTier(_TierBase):

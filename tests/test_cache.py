@@ -116,3 +116,144 @@ def test_numpy_jax_twins_agree(seed, depth):
     np.testing.assert_array_equal(
         np.sort(np.asarray(jc.tags).ravel()),
         np.sort(npc.tags.ravel()).astype(np.int32))
+
+
+def _access_sequential(state, nodes, future_counts):
+    """The one-id-at-a-time cache step that `cache_jax.access` replaces:
+    the oracle its set-parallel rounds must equal bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import cache_jax
+
+    sets = cache_jax._set_of(nodes, state.tags.shape[0])
+
+    def body(i, carry):
+        tags, reuse, hits, misses, bypasses, hit_mask, slot_out = carry
+        n, s, fc = nodes[i], sets[i], future_counts[i]
+        valid = n >= 0
+        row_tags, row_reuse = tags[s], reuse[s]
+        match = row_tags == n
+        is_hit = valid & jnp.any(match)
+        way_hit = jnp.argmax(match)
+        new_reuse_hit = row_reuse.at[way_hit].set(
+            jnp.maximum(row_reuse[way_hit] - 1, 0))
+        empty, safe = row_tags == -1, row_reuse == 0
+        has_empty, has_safe = jnp.any(empty), jnp.any(safe)
+        way_fill = jnp.where(has_empty, jnp.argmax(empty), jnp.argmax(safe))
+        can_fill = valid & ~is_hit & (has_empty | has_safe)
+        row_tags2 = jnp.where(can_fill, row_tags.at[way_fill].set(n),
+                              row_tags)
+        row_reuse2 = jnp.where(is_hit, new_reuse_hit,
+                               jnp.where(can_fill,
+                                         row_reuse.at[way_fill].set(fc),
+                                         row_reuse))
+        tags = tags.at[s].set(jnp.where(valid, row_tags2, row_tags))
+        reuse = reuse.at[s].set(jnp.where(valid, row_reuse2, row_reuse))
+        hits += is_hit.astype(hits.dtype)
+        misses += (valid & ~is_hit).astype(misses.dtype)
+        bypasses += (valid & ~is_hit
+                     & ~(has_empty | has_safe)).astype(bypasses.dtype)
+        hit_mask = hit_mask.at[i].set(is_hit)
+        way = jnp.where(is_hit, way_hit, way_fill)
+        slot_out = slot_out.at[i].set(
+            jnp.where(valid & (is_hit | can_fill), state.slots[s, way], -1))
+        return tags, reuse, hits, misses, bypasses, hit_mask, slot_out
+
+    B = nodes.shape[0]
+    init = (state.tags, state.reuse, state.hits, state.misses,
+            state.bypasses, jnp.zeros(B, bool), jnp.full(B, -1, jnp.int32))
+    tags, reuse, hits, misses, bypasses, hit_mask, slots = \
+        jax.jit(lambda c: jax.lax.fori_loop(0, B, body, c))(init)
+    return (state._replace(tags=tags, reuse=reuse, hits=hits, misses=misses,
+                           bypasses=bypasses), hit_mask, slots)
+
+
+def _push_sequential(state, nodes):
+    """The one-id-at-a-time `push_window` (oracle)."""
+    import jax
+    from repro.core import cache_jax
+
+    sets = cache_jax._set_of(nodes, state.tags.shape[0])
+
+    def body(i, reuse):
+        n, s = nodes[i], sets[i]
+        return reuse.at[s].add(((state.tags[s] == n) & (n >= 0))
+                               .astype(reuse.dtype))
+
+    reuse = jax.jit(lambda r: jax.lax.fori_loop(0, nodes.shape[0], body,
+                                                r))(state.reuse)
+    return state._replace(reuse=reuse)
+
+
+def _same_batch_set(num_sets, count, offset=0):
+    """`count` distinct ids that all hash to one set."""
+    from repro.core.software_cache import _hash_ids
+    ids = np.arange(offset, offset + 200_000)
+    ids = ids[_hash_ids(ids, num_sets) == _hash_ids(ids[:1], num_sets)[0]]
+    return ids[:count]
+
+
+def _exact_cases():
+    """(name, lines, ways, batches): each batch a (B,) int array, -1 pad."""
+    rng = np.random.default_rng(5)
+
+    def pad(ids, B):
+        out = np.full(B, -1, np.int64)
+        out[:len(ids)] = ids
+        return out
+
+    crowded = [pad(np.unique(rng.integers(0, 300, 24)), 24)
+               for _ in range(8)]
+    wide = [pad(np.unique(rng.integers(0, 5000, 400)), 512)
+            for _ in range(6)]
+    dups = [rng.integers(-1, 60, 40) for _ in range(6)]
+    padding = [np.full(16, -1, np.int64)] * 2
+    one_set = [pad(_same_batch_set(8, 20), 32),
+               pad(_same_batch_set(8, 12, 7), 16)]
+    # a set filled with lines pinned by reuse > 0 (each id sits where the
+    # test's future count is nonzero): the next ids of that set find no
+    # room and bypass
+    same = _same_batch_set(8, 10)
+    pinned = [np.array([-1, same[0], same[1], -1, same[2], same[3]]),
+              same[4:]]
+    return [("crowded_32x4", 32, 4, crowded), ("wide_256x8", 256, 8, wide),
+            ("duplicates", 32, 4, dups), ("padding_only", 32, 4, padding),
+            ("one_set", 64, 8, one_set), ("pinned_bypass", 32, 4, pinned)]
+
+
+@pytest.mark.parametrize("case", _exact_cases(), ids=lambda c: c[0])
+def test_access_rounds_equal_sequential_loop(case):
+    """Set-parallel rounds leave tags, reuse, counters, hit mask and slots
+    exactly as the sequential loop does; `push_window` likewise; the round
+    count is the batch's largest number of valid ids in one set."""
+    import jax.numpy as jnp
+    from repro.core import cache_jax
+    from repro.core.software_cache import _hash_ids
+
+    _, lines, ways, batches = case
+    par = seq = cache_jax.init_cache(lines, ways)
+    for b in batches:
+        ids = jnp.asarray(b, jnp.int32)
+        # look-ahead: announce the batch, so hits hold reservations
+        par = cache_jax.push_window(par, ids)
+        seq = _push_sequential(seq, ids)
+        np.testing.assert_array_equal(np.asarray(par.reuse),
+                                      np.asarray(seq.reuse))
+        fc = jnp.asarray((np.arange(len(b)) % 3) * (b >= 0), jnp.int32)
+        par, hit_p, slot_p = cache_jax.access(par, ids, fc)
+        seq, hit_s, slot_s = _access_sequential(seq, ids, fc)
+        for field in ("tags", "reuse", "slots", "hits", "misses",
+                      "bypasses"):
+            np.testing.assert_array_equal(np.asarray(getattr(par, field)),
+                                          np.asarray(getattr(seq, field)),
+                                          err_msg=field)
+        np.testing.assert_array_equal(np.asarray(hit_p), np.asarray(hit_s))
+        np.testing.assert_array_equal(np.asarray(slot_p), np.asarray(slot_s))
+        valid = b[b >= 0]
+        expect = (np.bincount(_hash_ids(valid, lines // ways)).max()
+                  if len(valid) else 0)
+        assert int(par.rounds) == expect
+    if case[0] == "pinned_bypass":
+        assert int(par.bypasses) > 0
+    if case[0] == "one_set":
+        assert int(par.rounds) == (batches[-1] >= 0).sum()

@@ -1,4 +1,6 @@
 """Device-resident GIDS tier: jittable cache + Pallas gather end-to-end."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -135,3 +137,28 @@ def test_fills_keep_one_writer_per_line(seed):
         for i in np.flatnonzero(hits & (slots == line)):
             assert refilled[i] == bool((writers < i).any())
     assert not refilled[~hits].any()
+
+
+def test_access_rounds_reach_the_gather_report():
+    """`GatherReport.access_rounds` is the device-store probe's round count,
+    the batch's largest number of ids in one cache set, and 0 for a probe
+    that holds only padding."""
+    from repro.core.feature_store import TieredFeatureStore
+    from repro.core.software_cache import _hash_ids
+    from repro.core.tiers import DeviceStoreTier, StorageTier
+
+    feats = np.random.default_rng(2).standard_normal((400, 16)) \
+        .astype(np.float32)
+    tier = DeviceStoreTier(feats, num_lines=64, ways=8)
+    fs = TieredFeatureStore([tier, StorageTier(feats)])
+    ids = np.unique(np.random.default_rng(3).integers(0, 400, 50))
+    rows, report = fs.gather(ids)
+    np.testing.assert_array_equal(rows, feats[ids])
+    expect = int(np.bincount(_hash_ids(ids, 8)).max())
+    assert report.access_rounds == tier.last_access_rounds == expect > 1
+    assert report == dataclasses.replace(report, access_rounds=0)
+    # an empty request probes a bucket of padding: no round runs
+    assert tier.probe(np.array([], np.int64)).shape == (0,)
+    assert tier.last_access_rounds == int(tier.store.cache.rounds) == 0
+    rows, report = fs.gather(np.array([], np.int64))
+    assert rows.shape == (0, 16) and report.access_rounds == 0

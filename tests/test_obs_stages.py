@@ -165,6 +165,7 @@ READERS = [
     ("loader.price_ms", 1e3 * 0.002 / 2),
     ("loader.h2d_mb", (3_000_000 + 1_000_000) / 1e6 / 2),
     ("loader.d2h_mb", (2_000_000 + 500_000) / 1e6 / 2),
+    ("device_tier.access_rounds", (11 + 9) / 2),
 ]
 
 
@@ -175,15 +176,16 @@ def test_loader_stage_readers(name, want):
     from gnnbench import cells
     from gnnbench.loop import Record, Step
 
-    def step(h2d, d2h):
-        report = SimpleNamespace(h2d_bytes=h2d, d2h_bytes=d2h)
+    def step(h2d, d2h, rounds):
+        report = SimpleNamespace(h2d_bytes=h2d, d2h_bytes=d2h,
+                                 access_rounds=rounds)
         return Step(t0=0.0, t1=1.0, unique=1, report=report, loss=None)
 
     spans = [("plan_next", 0.01), ("execute", 2.0), ("stage_rows", 0.1),
              ("h2d", 0.04), ("device_wait", 1.5), ("d2h", 0.06),
              ("price", 0.002), ("stage_rows", 0.2)]
-    rec = Record(cell=None, steps=[step(3_000_000, 2_000_000),
-                                   step(1_000_000, 500_000)],
+    rec = Record(cell=None, steps=[step(3_000_000, 2_000_000, 11),
+                                   step(1_000_000, 500_000, 9)],
                  window_s=2.0, spans=spans, profiled=[], trace=None,
                  peak=None)
     read = cells.reader(name, BENCH)
